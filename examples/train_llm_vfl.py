@@ -4,7 +4,7 @@ VFL round (per-vehicle replicas on the data axis, VEDS-gated aggregation).
 This is the big-model version of the paper's pipeline: vehicles = data-axis
 groups of a jax mesh, model upload = the masked psum in fl/vfl.py.
 
-  PYTHONPATH=src python examples/train_llm_vfl.py --rounds 50
+  JAX_PLATFORMS=cpu PYTHONPATH=src python examples/train_llm_vfl.py --rounds 50
 (thin wrapper over repro.launch.train with a larger reduced config)
 """
 import sys

@@ -20,7 +20,7 @@ layout on the left, batched layout on the right:
 
 DT candidate scoring (Prop. 1 + objective (21a)) is routed through the
 `veds_score` Pallas kernel: the [B, S] candidate grid is flattened into the
-kernel's tiled 1-D candidate layout. `use_kernel=False` keeps the pure-jnp
+kernel's lane-dense [rows, 128] candidate tiles. `use_kernel=False` keeps the pure-jnp
 reference path, which tests check against the kernel (see DESIGN.md §6).
 """
 from __future__ import annotations
@@ -82,7 +82,7 @@ def _dt_candidates(w, qs, g_sr, eligible, prm: lyp.VedsParams,
     """Closed-form DT (Prop. 1) for the whole [B, S] candidate grid.
 
     Returns (y, p, z), each [B, S]. With `use_kernel` the grid is flattened
-    into the Pallas kernel's 1-D tiled candidate layout (interpret mode off
+    into the Pallas kernel's [rows, 128] candidate tiles (interpret mode off
     TPU); otherwise the pure-jnp reference math runs. Both zero p/z on
     ineligible candidates and pin their objective to NEG.
     """

@@ -136,14 +136,10 @@ def run_fl(key: jax.Array, params, loss_fn: Callable,
     The fused streaming path also reports `dispatches` — how many jitted
     rollout segments the run launched (1 with in-scan eval or no eval:
     the whole run is one XLA program with a single trailing
-    `block_until_ready`).
+    `block_until_ready`) — and `loss`, the weighted mean local training
+    loss of each recorded round.
     """
-    mob = ManhattanParams(v_max=sim.v_max)
-    ch = ChannelParams()
-    prm = VedsParams(alpha=sim.alpha, V=sim.V, Q=sim.q_bits, slot=0.1,
-                     ipm_warm_iters=sim.ipm_warm_iters)
-    sc = ScenarioParams(n_sov=sim.n_sov, n_opv=sim.n_opv,
-                        n_slots=sim.n_slots, batch_size=sim.batch_size)
+    sc, mob, ch, prm = _sim_params(sim)
     sched = get_scheduler(sim.scheduler)
 
     if sim.streaming and sim.fused:
@@ -247,6 +243,17 @@ def run_fl(key: jax.Array, params, loss_fn: Callable,
     return history
 
 
+def _sim_params(sim: FLSimConfig):
+    """The scenario, mobility, channel and VEDS parameters of a run."""
+    mob = ManhattanParams(v_max=sim.v_max)
+    ch = ChannelParams()
+    prm = VedsParams(alpha=sim.alpha, V=sim.V, Q=sim.q_bits, slot=0.1,
+                     ipm_warm_iters=sim.ipm_warm_iters)
+    sc = ScenarioParams(n_sov=sim.n_sov, n_opv=sim.n_opv,
+                        n_slots=sim.n_slots, batch_size=sim.batch_size)
+    return sc, mob, ch, prm
+
+
 def _stream_cfg(sim: FLSimConfig) -> StreamConfig:
     return StreamConfig(n_rounds=sim.rounds, batch=1,
                         carry_queues=sim.carry_queues,
@@ -267,6 +274,43 @@ def _stream_draws(key: jax.Array, sim: FLSimConfig):
     return k_sched, sel, mb_u
 
 
+def _fused_evals(sim: FLSimConfig, eval_fn, eval_every: int):
+    R = sim.rounds
+    return ([] if eval_fn is None else
+            [r for r in range(R) if r % eval_every == 0 or r == R - 1])
+
+
+def _fused_inputs(key, params, sim: FLSimConfig, sc, mob, ch):
+    """(cfg, carry, keys [R], sel [R, 1, S], mb_u [R, 1, S, bs]) of a
+    fused run: the streaming draws plus the B = 1 rollout carry."""
+    cfg = _stream_cfg(sim)
+    k_sched, sel, mb_u = _stream_draws(key, sim)
+    keys = round_keys(k_sched, cfg, sim.rounds)
+    carry = init_carry(k_sched, sc, mob, cfg, params, ch=ch)
+    return cfg, carry, keys, sel[:, None], mb_u[:, None]
+
+
+def fused_program(key, params, loss_fn, shards: ClientShards,
+                  sim: FLSimConfig, eval_fn=None, eval_every: int = 5):
+    """The one-dispatch fused run (in-scan eval, or no eval) as
+    `(seg, args)`: `seg(*args)` is the whole run `run_fl` dispatches, so
+    `seg.lower(*args)` is the program it compiles."""
+    sc, mob, ch, prm = _sim_params(sim)
+    R = sim.rounds
+    cfg, carry, keys, sel, mb_u = _fused_inputs(key, params, sim, sc,
+                                                mob, ch)
+    seg = _fused_segment(loss_fn, sim.scheduler, sc, mob, ch, prm,
+                         dataclasses.replace(cfg, n_rounds=0), sim.lr,
+                         max(1, sim.fused_unroll), eval_fn,
+                         max(1, sim.fused_history_chunk))
+    ev = jnp.zeros((R,), bool)
+    evals = _fused_evals(sim, eval_fn, eval_every)
+    if evals:
+        ev = ev.at[jnp.asarray(evals)].set(True)
+    return seg, (carry, keys, sel, mb_u, shards, jnp.arange(R),
+                 jnp.ones((R,), bool), ev)
+
+
 def _run_fused(key, params, loss_fn, shards: ClientShards,
                sim: FLSimConfig, sc, mob, ch, prm, eval_fn, eval_every):
     """The fused path. Default (`eval_in_scan`, or no eval_fn): the whole
@@ -277,27 +321,14 @@ def _run_fused(key, params, loss_fn, shards: ClientShards,
     eval_fn per segment, kept for non-traceable eval functions), every
     segment padded to one compiled shape."""
     R = sim.rounds
-    cfg = _stream_cfg(sim)
-    k_sched, sel, mb_u = _stream_draws(key, sim)
-    sel = sel[:, None]                                       # [R, 1, S]
-    mb_u = mb_u[:, None]                                     # [R, 1, S, bs]
-    keys = round_keys(k_sched, cfg, R)
-    carry = init_carry(k_sched, sc, mob, cfg, params, ch=ch)
-    evals = ([] if eval_fn is None else
-             [r for r in range(R) if r % eval_every == 0 or r == R - 1])
+    evals = _fused_evals(sim, eval_fn, eval_every)
     history = {"round": [], "time": [], "n_success": [], "metric": [],
-               "scheduled_rounds": R, "dispatches": 0}
+               "loss": [], "scheduled_rounds": R, "dispatches": 0}
 
     if eval_fn is None or sim.eval_in_scan:
-        seg_fn = _fused_segment(loss_fn, sim.scheduler, sc, mob, ch, prm,
-                                dataclasses.replace(cfg, n_rounds=0),
-                                sim.lr, max(1, sim.fused_unroll),
-                                eval_fn, max(1, sim.fused_history_chunk))
-        ev = jnp.zeros((R,), bool)
-        if evals:
-            ev = ev.at[jnp.asarray(evals)].set(True)
-        res = seg_fn(carry, keys, sel, mb_u, shards, jnp.arange(R),
-                     jnp.ones((R,), bool), ev)
+        seg_fn, args = fused_program(key, params, loss_fn, shards, sim,
+                                     eval_fn, eval_every)
+        res = seg_fn(*args)
         history["dispatches"] = 1
         # the ONE trailing sync: everything read below is a materialized
         # buffer, not a new device round-trip
@@ -305,13 +336,17 @@ def _run_fused(key, params, loss_fn, shards: ClientShards,
         if evals:
             n_succ = np.asarray(res.outputs.n_success[:, 0])
             met = np.asarray(res.metric[:, 0])
+            loss = np.asarray(res.loss[:, 0])
             for r in evals:
                 history["round"].append(r)
                 history["time"].append((r + 1) * sim.n_slots * prm.slot)
                 history["n_success"].append(int(n_succ[r]))
                 history["metric"].append(float(met[r]))
+                history["loss"].append(float(loss[r]))
         return history
 
+    cfg, carry, keys, sel, mb_u = _fused_inputs(key, params, sim, sc, mob,
+                                                ch)
     K = max(1, sim.fused_history_chunk)
     seg_fn = _fused_segment(loss_fn, sim.scheduler, sc, mob, ch, prm,
                             dataclasses.replace(cfg, n_rounds=0),
@@ -351,6 +386,7 @@ def _run_fused(key, params, loss_fn, shards: ClientShards,
         history["time"].append((r + 1) * sim.n_slots * prm.slot)
         history["n_success"].append(
             int(res.outputs.n_success[n - 1, 0]))
+        history["loss"].append(float(res.loss[n - 1, 0]))
         history["metric"].append(float(eval_fn(
             jax.tree.map(lambda x: x[0], res.params))))
         r0 = cut

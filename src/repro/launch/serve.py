@@ -880,10 +880,11 @@ def drive(cfg: ServeConfig, *, n_clients: int = 8, n_requests: int = 4,
           rate_hz: float = 0.0, window_s: Optional[float] = None,
           baseline: bool = True, seed: int = 0) -> Dict[str, object]:
     """Build a service, drive it under synthetic load, and return the
-    metrics summary — plus the sequential per-request baseline (a
-    `batch=1` service dispatching every request alone, the B=1 lower
-    bound) and the aggregate rounds/s speedup over it. `n_rounds` may be
-    a sequence for a mixed-round-count load (the tiered workload)."""
+    metrics summary and the batched service's `responses` — plus the
+    sequential per-request baseline (a `batch=1` service dispatching
+    every request alone, the B=1 lower bound) and the aggregate rounds/s
+    speedup over it. `n_rounds` may be a sequence for a
+    mixed-round-count load (the tiered workload)."""
     if n_rounds is None:
         n_rounds = cfg.horizons[-1]
 
@@ -895,27 +896,27 @@ def drive(cfg: ServeConfig, *, n_clients: int = 8, n_requests: int = 4,
             async with BatchServer(service, window_s=w,
                                    max_batch=mb) as srv:
                 if rate_hz > 0:
-                    await poisson_load(srv, n_clients=n_clients,
-                                       rate_hz=rate_hz,
-                                       n_requests=n_requests,
-                                       n_rounds=n_rounds, seed=seed)
-                else:
-                    await closed_loop_load(srv, n_clients=n_clients,
-                                           n_requests=n_requests,
-                                           n_rounds=n_rounds, seed=seed)
+                    return await poisson_load(srv, n_clients=n_clients,
+                                              rate_hz=rate_hz,
+                                              n_requests=n_requests,
+                                              n_rounds=n_rounds, seed=seed)
+                return await closed_loop_load(srv, n_clients=n_clients,
+                                              n_requests=n_requests,
+                                              n_rounds=n_rounds, seed=seed)
 
-        asyncio.run(go())
-        return service.metrics.summary()
+        responses = asyncio.run(go())
+        return service.metrics.summary(), responses
 
     w = float(cfg.window_s if window_s is None else window_s)
-    out: Dict[str, object] = {
-        "batched": load(SchedulingService(cfg), w, int(cfg.batch))}
+    out: Dict[str, object] = {}
+    out["batched"], out["responses"] = load(SchedulingService(cfg), w,
+                                            int(cfg.batch))
     if baseline:
         # the B=1 lower bound keeps the horizon ladder but has no
         # occupancy to bucket (an explicit batch_tiers would not fit)
         seq = SchedulingService(dataclasses.replace(cfg, batch=1,
                                                     batch_tiers=None))
-        out["sequential"] = load(seq, 0.0, 1)
+        out["sequential"], _ = load(seq, 0.0, 1)
         out["speedup"] = (out["batched"]["rounds_per_s"]
                           / out["sequential"]["rounds_per_s"])
     return out
@@ -966,7 +967,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                 n_rounds=args.rounds, rate_hz=args.rate,
                 baseline=not args.no_baseline, seed=args.seed)
     if args.json:
-        print(json.dumps(out))
+        print(json.dumps({k: v for k, v in out.items()
+                          if k != "responses"}))
         return 0
     b = out["batched"]
     print(f"batched  B={args.batch} window={args.window_ms}ms: "

@@ -4,8 +4,11 @@ Trains a reduced variant of any assigned architecture with the full paper
 pipeline: Manhattan mobility -> 3GPP channels -> VEDS scheduling -> local SGD
 -> masked aggregation, on synthetic LM data.
 
-  PYTHONPATH=src python -m repro.launch.train --arch qwen3-32b \
-      --rounds 20 --devices 8 --vehicles 4
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
+      --arch qwen3-32b --rounds 20 --devices 8 --vehicles 4
+
+With `JAX_PLATFORMS=cpu` the mesh is `--devices` fake host devices;
+otherwise it takes `--devices` of the accelerators JAX finds.
 """
 from __future__ import annotations
 
@@ -30,8 +33,12 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}")
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        # fake host devices stand in for the mesh on CPU only; on an
+        # accelerator the mesh is the chips JAX finds
+        os.environ.setdefault(
+            "XLA_FLAGS",
+            f"--xla_force_host_platform_device_count={args.devices}")
     import jax
     import jax.numpy as jnp
     from repro.channel.mobility import ManhattanParams

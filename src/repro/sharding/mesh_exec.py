@@ -66,8 +66,7 @@ def fleet_mesh(n_devices: Optional[int] = None, axis: str = "data") -> Mesh:
     n = len(devs) if n_devices is None else int(n_devices)
     if n > len(devs):
         raise ValueError(f"asked for {n} devices, have {len(devs)} "
-                         "(set XLA_FLAGS=--xla_force_host_platform_"
-                         "device_count=<n> before importing jax)")
+                         f"({devs[0].platform})")
     return Mesh(np.asarray(devs[:n]), (axis,))
 
 
@@ -86,67 +85,81 @@ def cell_spec(rules: LogicalRules, ndim: int) -> P:
     return P(rules.mesh_axis("cell"), *([None] * max(ndim - 1, 0)))
 
 
-def place_fleet(mesh: Mesh, fleet: FleetState,
-                rules: Optional[LogicalRules] = None) -> FleetState:
-    """Commit a FleetState to the mesh under `fleet_spec`, with `rsu_xy`
-    replicated (the exchange's distance matrix reads every RSU position
-    on every shard — see `rules.fleet_spec`)."""
+def fleet_shardings(mesh: Mesh, fleet: FleetState,
+                    rules: Optional[LogicalRules] = None) -> FleetState:
+    """The NamedSharding of every FleetState leaf: `fleet_spec`, with
+    `rsu_xy` replicated (the exchange's distance matrix reads every RSU
+    position on every shard — see `rules.fleet_spec`). Leaves may be
+    arrays or shapes."""
     rules = rules or default_rules()
-    reps = {}
-    for f in dataclasses.fields(fleet):
-        x = getattr(fleet, f.name)
-        spec = P() if f.name == "rsu_xy" else fleet_spec(rules, x.ndim)
-        reps[f.name] = jax.device_put(x, NamedSharding(mesh, spec))
-    return FleetState(**reps)
+    return FleetState(**{
+        f.name: NamedSharding(mesh, P() if f.name == "rsu_xy" else
+                              fleet_spec(rules, getattr(fleet, f.name).ndim))
+        for f in dataclasses.fields(fleet)})
 
 
-def place_carry(mesh: Mesh, carry: RolloutCarry,
-                rules: Optional[LogicalRules] = None) -> RolloutCarry:
-    """Commit a fused-rollout carry: FleetState under `fleet_spec`
+def carry_shardings(mesh: Mesh, carry: RolloutCarry,
+                    rules: Optional[LogicalRules] = None) -> RolloutCarry:
+    """Shardings of a fused-rollout carry: FleetState under `fleet_spec`
     (queue carries / params / optimizer state under the cell spec)."""
     rules = rules or default_rules()
 
-    def put_cell(t):
-        if t is None:
-            return None
+    def cell(t):
         return jax.tree.map(
-            lambda x: jax.device_put(
-                x, NamedSharding(mesh, cell_spec(rules, x.ndim))), t)
+            lambda x: NamedSharding(mesh, cell_spec(rules, x.ndim)), t)
 
-    sched = (place_fleet(mesh, carry.sched, rules)
-             if isinstance(carry.sched, FleetState)
-             else put_cell(carry.sched))
-    return RolloutCarry(sched=sched, params=put_cell(carry.params),
-                        opt_state=put_cell(carry.opt_state))
+    sched = (fleet_shardings(mesh, carry.sched, rules)
+             if isinstance(carry.sched, FleetState) else cell(carry.sched))
+    return RolloutCarry(sched=sched, params=cell(carry.params),
+                        opt_state=cell(carry.opt_state))
 
 
-def place_batch(mesh: Mesh, tree,
-                rules: Optional[LogicalRules] = None):
-    """Commit `[R, B, ...]` scan xs (sel / mb_u) under
+def batch_shardings(mesh: Mesh, tree,
+                    rules: Optional[LogicalRules] = None):
+    """Shardings of `[R, B, ...]` scan xs (sel / mb_u) under
     `fused_batch_spec`: round axis scanned, cell axis sharded."""
     rules = rules or default_rules()
     return jax.tree.map(
-        lambda x: jax.device_put(
-            x, NamedSharding(mesh, fused_batch_spec(rules, x.ndim))),
+        lambda x: NamedSharding(mesh, fused_batch_spec(rules, x.ndim)),
         tree)
 
 
-def place_shards(mesh: Mesh, shards: ClientShards,
-                 rules: Optional[LogicalRules] = None) -> ClientShards:
-    """Commit the padded client data under the "client" rule when the
+def shards_shardings(mesh: Mesh, shards: ClientShards,
+                     rules: Optional[LogicalRules] = None) -> ClientShards:
+    """Shardings of the padded client data: the "client" rule when the
     client count divides the mesh, replicated otherwise (the per-round
     minibatch gather indexes arbitrary clients per cell, so GSPMD emits
     a collective gather from a sharded layout — correct either way)."""
     rules = rules or default_rules()
-    C = shards.n_clients
-    ax = rules.mesh_axis("client") if C % num_vehicles(mesh) == 0 else None
+    ax = (rules.mesh_axis("client")
+          if shards.n_clients % num_vehicles(mesh) == 0 else None)
+    return jax.tree.map(
+        lambda x: NamedSharding(mesh, P(ax, *([None] * (x.ndim - 1)))),
+        shards)
 
-    def put(x):
-        spec = P(ax, *([None] * (x.ndim - 1)))
-        return jax.device_put(x, NamedSharding(mesh, spec))
 
-    return ClientShards(data=jax.tree.map(put, shards.data),
-                        n_samples=put(shards.n_samples))
+def place_fleet(mesh: Mesh, fleet: FleetState,
+                rules: Optional[LogicalRules] = None) -> FleetState:
+    """Commit a FleetState to the mesh under `fleet_shardings`."""
+    return jax.device_put(fleet, fleet_shardings(mesh, fleet, rules))
+
+
+def place_carry(mesh: Mesh, carry: RolloutCarry,
+                rules: Optional[LogicalRules] = None) -> RolloutCarry:
+    """Commit a fused-rollout carry under `carry_shardings`."""
+    return jax.device_put(carry, carry_shardings(mesh, carry, rules))
+
+
+def place_batch(mesh: Mesh, tree,
+                rules: Optional[LogicalRules] = None):
+    """Commit `[R, B, ...]` scan xs under `batch_shardings`."""
+    return jax.device_put(tree, batch_shardings(mesh, tree, rules))
+
+
+def place_shards(mesh: Mesh, shards: ClientShards,
+                 rules: Optional[LogicalRules] = None) -> ClientShards:
+    """Commit the padded client data under `shards_shardings`."""
+    return jax.device_put(shards, shards_shardings(mesh, shards, rules))
 
 
 # Whole-run steps, cached so repeated rollouts (benchmark sweeps, CI

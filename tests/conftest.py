@@ -11,28 +11,13 @@ import contextlib  # noqa: E402
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
+
 # Persistent XLA compilation cache: tier-1 is compile-dominated (~100+
 # distinct jitted programs at a few seconds each), and the cache works on
 # the CPU backend — warm re-runs skip XLA entirely (tracing still runs).
 # CI restores .jax_cache via actions/cache; locally it just accumulates.
-try:
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:                       # older jax: no persistent cache
-    pass
-
-# The sharded decode/train paths target the explicit-axis-type mesh APIs
-# (jax.sharding.AxisType, jax.set_mesh, jax.shard_map). On older jax
-# (e.g. 0.4.x) those tests cannot run at all — skip them with a clear
-# reason instead of erroring the suite.
-HAS_MESH_API = (hasattr(jax.sharding, "AxisType")
-                and hasattr(jax, "shard_map"))
-requires_mesh_api = pytest.mark.skipif(
-    not HAS_MESH_API,
-    reason="needs jax>=0.7 mesh APIs (jax.sharding.AxisType / "
-           "jax.shard_map); toolchain has jax " + jax.__version__)
+use_compile_cache()
 
 
 @contextlib.contextmanager
@@ -73,8 +58,5 @@ def mark_slow_unless(values, quick):
 
 @pytest.fixture(scope="session")
 def single_mesh():
-    if not HAS_MESH_API:
-        pytest.skip("single_mesh needs jax.sharding.AxisType "
-                    "(jax>=0.7); toolchain has jax " + jax.__version__)
     return jax.make_mesh((1,), ("model",),
                          axis_types=(jax.sharding.AxisType.Auto,))

@@ -10,8 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_mesh_api
-
 from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.data.synthetic import lm_batch, partition_labels
 from repro.optim import adam, momentum, sgd
@@ -115,11 +113,10 @@ _DISTRIBUTED = textwrap.dedent("""
 
 
 @pytest.mark.slow
-@requires_mesh_api
 def test_vfl_round_distributed_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"        # fake host devices, never a chip
     r = subprocess.run([sys.executable, "-c", _DISTRIBUTED], env=env,
                        capture_output=True, text=True, timeout=900)
     assert "DISTRIBUTED_OK" in r.stdout, r.stdout + r.stderr

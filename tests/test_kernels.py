@@ -7,7 +7,10 @@ import pytest
 from repro.kernels.fedavg_agg.ops import fedavg_agg_tpu
 from repro.kernels.flash_attention.ops import flash_attention_tpu
 from repro.kernels.ssd_scan.ops import ssd_scan_tpu
-from repro.kernels.veds_score.ops import veds_dt_score_tpu
+from repro.kernels.veds_score.ops import (tile_rows, to_tiles,
+                                          veds_dt_score_tpu)
+from repro.kernels.veds_score.veds_score import (LANES, NEG,
+                                                 veds_dt_score_pallas)
 
 KEY = jax.random.key(0)
 
@@ -72,18 +75,29 @@ def test_fedavg_all_failed_keeps_old():
     np.testing.assert_allclose(np.asarray(out), np.asarray(old))
 
 
-@pytest.mark.parametrize("c,block", [(100, 32), (256, 256), (17, 8)])
-def test_veds_score(c, block):
+@pytest.mark.parametrize("c", [100, 256, 17, 300, 2560])
+def test_veds_score(c):
+    """Lane-dense tiles: one block spanning every row (100 / 256 / 17 /
+    300 -> 1 / 2 / 1 / 3 rows) or several 8-row blocks (2560 -> 20 rows
+    padded to 24), every padded candidate ineligible."""
     g = jnp.abs(jax.random.normal(jax.random.fold_in(KEY, 14), (c,))) * 1e-11
     q = jnp.abs(jax.random.normal(jax.random.fold_in(KEY, 15), (c,))) * 0.1
     w = jnp.abs(jax.random.normal(jax.random.fold_in(KEY, 16), (c,))) * 1e-7
     e = jax.random.bernoulli(jax.random.fold_in(KEY, 17), 0.8, (c,))
     kw = dict(V=0.2, kappa=0.1, bw=20e6, noise=8e-14, p_max=0.3)
-    outs_k = veds_dt_score_tpu(g, q, w, e, block_c=block, **kw)
+    outs_k = veds_dt_score_tpu(g, q, w, e, **kw)
     outs_r = veds_dt_score_tpu(g, q, w, e, force_ref=True, **kw)
     for a, b in zip(outs_k, outs_r):
+        assert a.shape == (c,)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
+    # the tail padding the count up to the tiling scores as masked
+    rows, blk = tile_rows(c)
+    assert rows % blk == 0 and rows * LANES >= c
+    tiles = veds_dt_score_pallas(*(to_tiles(x, rows) for x in (g, q, w, e)),
+                                 block_rows=blk, **kw)
+    y, p, z = (np.asarray(t).reshape(-1)[c:] for t in tiles)
+    assert (y == NEG).all() and not p.any() and not z.any()
 
 
 @pytest.mark.parametrize("b,s,block", [(1, 8, 8), (4, 8, 16), (16, 24, 128),

@@ -121,7 +121,10 @@ if HAS_HYPOTHESIS:
         assert float(hi) >= float(lo) - 1e-12
 
     @settings(max_examples=50, deadline=None)
-    @given(st.floats(0.0, 5e7, **finite), st.floats(0.0, 5e7, **finite))
+    # f32 draws: update_zeta computes in f32, and a wider zeta would be
+    # rounded on the way in, before the function sees it
+    @given(st.floats(0.0, 5e7, width=32, **finite),
+           st.floats(0.0, 5e7, width=32, **finite))
     def test_update_zeta_saturates_property(zeta, z):
         out = float(update_zeta(jnp.asarray(zeta), jnp.asarray(z), PRM))
         assert out <= PRM.Q + 1e-3
