@@ -40,6 +40,7 @@ from repro.channel.v2x import ChannelParams, channel_gain
 from repro.core.lyapunov import VedsParams
 from repro.core.solver import p4_seed_table
 from repro.core.veds import RoundInputs
+from repro.obs import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -297,6 +298,7 @@ def migrated_fraction(fleet0: FleetState, fleet1: FleetState) -> float:
                           for b in range(B)]))
 
 
+@scope("exchange")
 def exchange_fleet(fleet: FleetState, mob: ManhattanParams) -> FleetState:
     """Cross-cell vehicle exchange: hand every vehicle to its nearest RSU.
 
@@ -434,6 +436,7 @@ def _fleet_cell_round(key: jax.Array, pos, d, speed, jitter, allowance,
     return st, rnd, sov_idx, opv_idx, cov0
 
 
+@scope("fleet")
 def fleet_round(key: jax.Array, fleet: FleetState, sc: ScenarioParams,
                 mob: ManhattanParams, ch: ChannelParams,
                 prm: VedsParams, *,

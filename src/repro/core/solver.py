@@ -23,6 +23,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scope
+
 
 def dt_power_opt(cw: jax.Array, q: jax.Array, gain: jax.Array,
                  noise: float, p_max: float) -> jax.Array:
@@ -88,6 +90,7 @@ def _polish_count(n_it: int, iters: int) -> int:
     return 10 if n_it == iters else max(2, (10 * n_it) // iters)
 
 
+@scope("p4")
 def solve_p4(cw: jax.Array, a: jax.Array, q: jax.Array, d: jax.Array,
              p_max: jax.Array, *, iters: int = 25,
              mu_final: float = 1e-3, p_init=None, warm_iters: int = 0,

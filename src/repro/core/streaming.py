@@ -85,6 +85,7 @@ from repro.core.scenario import (FleetState, ScenarioParams,
                                  exchange_fleet, fleet_round, init_fleet,
                                  make_round_batch, rsu_grid)
 from repro.core.scheduler import RoundOutputs, Scheduler, SchedulerCarry
+from repro.obs import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -280,6 +281,7 @@ def warm_p4(sched: Scheduler, prm: VedsParams) -> bool:
         getattr(sched, "enable_cot", False))
 
 
+@scope("sched")
 def sched_round_step(state: SchedState, k: jax.Array, sched: Scheduler,
                      sc: ScenarioParams, mob: ManhattanParams,
                      ch: ChannelParams, prm: VedsParams, cfg: StreamConfig):

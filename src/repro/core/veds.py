@@ -38,6 +38,7 @@ from repro.core.scheduler import (RoundOutputs, SchedulerCarry, init_queues,
                                   masked_e_cp, unbatch)
 from repro.core.solver import dt_power_opt, solve_p4
 from repro.kernels.veds_score.ops import veds_dt_score_tpu
+from repro.obs import scope
 
 LN2 = 0.6931471805599453
 NEG = -1e30
@@ -77,6 +78,7 @@ class RoundInputs:
         return jax.tree.map(lambda x: x[b], self)
 
 
+@scope("dt_score")
 def _dt_candidates(w, qs, g_sr, eligible, prm: lyp.VedsParams,
                    ch: ChannelParams, use_kernel: bool = True):
     """Closed-form DT (Prop. 1) for the whole [B, S] candidate grid.

@@ -48,6 +48,7 @@ from repro.core.streaming import (StreamConfig, cast_sched_state,
                                   promote_sched_state, sched_round_step,
                                   sched_state0, validate_stream_config)
 from repro.data.synthetic import pad_client_shards
+from repro.obs import register, scope
 
 
 @jax.tree_util.register_dataclass
@@ -318,6 +319,7 @@ def fused_rollout(keys: jax.Array, sel: jax.Array, mb_u: jax.Array,
     if eval_mask is None:
         eval_mask = jnp.zeros((R,), bool)
 
+    @scope("step")
     def train_cell(p, os_, sel_c, u_c, mask_c, r):
         losses, grads, nf = local_grads(p, loss_fn, shards, sel_c, u_c)
         new_p, new_os = fedavg_apply(p, grads, mask_c, nf, lr=lr,
@@ -471,4 +473,4 @@ def fused_segment(loss_fn: Callable, sched_name: str, sc, mob, ch, prm,
                              eval_mask=ev, unroll=unroll,
                              history_chunk=history_chunk)
 
-    return seg
+    return register("fused_segment", seg)

@@ -68,4 +68,5 @@ def veds_dt_score_pallas(g, q, w, e, *, V: float, kappa: float, bw: float,
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct(g.shape, jnp.float32)] * 3,
         interpret=interpret,
+        name="veds_dt_score",
     )(g, q, w, e)

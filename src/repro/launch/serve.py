@@ -88,6 +88,7 @@ from repro.core.streaming import StreamConfig, pack_cells, unpack_cell
 # ladder: sharing it means a service, the simulator, and a test with
 # matching shapes share one executable per (occupancy entry, horizon)
 from repro.fl.engine import ClientShards, fused_segment, init_carry
+from repro.obs import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -439,7 +440,8 @@ class SessionStore:
         host = self._spilled.pop(session, None)
         if host is None:
             return None
-        carry = jax.tree.map(jnp.asarray, host)
+        with span("serve.restore"):
+            carry = jax.tree.map(jnp.asarray, host)
         if self.metrics is not None:
             self.metrics.n_restores += 1
         self.put(session, carry)
@@ -454,7 +456,8 @@ class SessionStore:
         while (self.max_sessions is not None
                and len(self._hot) > self.max_sessions):
             cold, c = self._hot.popitem(last=False)
-            self._spilled[cold] = jax.tree.map(np.asarray, c)
+            with span("serve.spill"):
+                self._spilled[cold] = jax.tree.map(np.asarray, c)
             if self.metrics is not None:
                 self.metrics.n_spills += 1
 
@@ -622,42 +625,58 @@ class SchedulingService:
                 raise ValueError(f"n_rounds={r.n_rounds} outside the "
                                  f"compiled horizon 1..{max_L}")
         L, B = self.route(reqs) if _tier is None else _tier
-        carries = [self.session_carry(r.session) for r in reqs]
-        cols = [_padded_draws(int(r.n_rounds), L, self.shards.n_clients,
-                              S, cfg.batch_size)(int(r.seed))
-                for r in reqs]
-        # pad to the tier occupancy HERE, on the host (replicas of slot
-        # 0 under all-inactive columns): `_assemble` then always traces
-        # at arity B, so a window of any occupancy reuses the rung's one
-        # warmed trace instead of compiling per occupancy mid-load
-        n_pad = B - len(reqs)
-        actives = [c[3] for c in cols] + [self._off[L]] * n_pad
-        carries = carries + [carries[0]] * n_pad
-        cols = cols + [cols[0]] * n_pad
-        carry, keys, sel, mb_u, active = _assemble(
-            tuple(carries), tuple(cols), tuple(actives))
-        res = self._seg[B](carry, keys, sel, mb_u, self.shards,
-                           self._steps[L], active, self._ev[L])
-        # always split the tier's full B cells (padding slices are lazy
-        # views): a static per-tier arity means occupancy changes within
-        # a bucket never re-trace
-        fleets = _split_cells(res.fleet, B)
-        params = _split_cells(res.params, B)
-        opts = (None,) * B if res.opt_state is None else \
-            _split_cells(res.opt_state, B)
-        # one device->host transfer per output array, numpy slicing after
-        succ = np.asarray(res.outputs.success)
-        n_succ = np.asarray(res.outputs.n_success)
-        loss = np.asarray(res.loss)
+        tier = f"L{L}xB{B}"
+        # host spans (DESIGN.md §15): the dispatch and each of
+        # its host stages, on the thread that runs it
+        with span("serve.run_batch", tier=tier, requests=len(reqs),
+                  dispatch=sum(self.metrics.tier_hits.values())):
+            with span("serve.store"):
+                carries = [self.session_carry(r.session) for r in reqs]
+            with span("serve.draws"):
+                cols = [_padded_draws(int(r.n_rounds), L,
+                                      self.shards.n_clients, S,
+                                      cfg.batch_size)(int(r.seed))
+                        for r in reqs]
+            # pad to the tier occupancy HERE, on the host (replicas of
+            # slot 0 under all-inactive columns): `_assemble` then always
+            # traces at arity B, so a window of any occupancy reuses the
+            # rung's one warmed trace instead of compiling per occupancy
+            # mid-load
+            n_pad = B - len(reqs)
+            actives = [c[3] for c in cols] + [self._off[L]] * n_pad
+            carries = carries + [carries[0]] * n_pad
+            cols = cols + [cols[0]] * n_pad
+            with span("serve.assemble"):
+                carry, keys, sel, mb_u, active = _assemble(
+                    tuple(carries), tuple(cols), tuple(actives))
+            with span("serve.segment"):
+                res = self._seg[B](carry, keys, sel, mb_u, self.shards,
+                                   self._steps[L], active, self._ev[L])
+            # always split the tier's full B cells (padding slices are
+            # lazy views): a static per-tier arity means occupancy changes
+            # within a bucket never re-trace
+            with span("serve.split"):
+                fleets = _split_cells(res.fleet, B)
+                params = _split_cells(res.params, B)
+                opts = (None,) * B if res.opt_state is None else \
+                    _split_cells(res.opt_state, B)
+            # one device->host transfer per output array, numpy slicing
+            # after: the host waits here for the dispatch to finish
+            with span("serve.to_host"):
+                succ = np.asarray(res.outputs.success)
+                n_succ = np.asarray(res.outputs.n_success)
+                loss = np.asarray(res.loss)
+            with span("serve.store"):
+                for b, r in enumerate(reqs):
+                    self.sessions.put(r.session, RolloutCarry(
+                        sched=fleets[b], params=params[b],
+                        opt_state=opts[b]))
         out = []
         for b, r in enumerate(reqs):
-            self.sessions.put(r.session, RolloutCarry(
-                sched=fleets[b], params=params[b], opt_state=opts[b]))
             R = int(r.n_rounds)
             out.append(ServeResponse(
                 session=r.session, n_rounds=R, success=succ[:R, b],
-                n_success=n_succ[:R, b], loss=loss[:R, b],
-                tier=f"L{L}xB{B}"))
+                n_success=n_succ[:R, b], loss=loss[:R, b], tier=tier))
         if not self._warming:
             self.metrics.observe_dispatch(reqs, L, B)
         return out
