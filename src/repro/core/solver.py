@@ -3,7 +3,9 @@
 * P3.1 (direct transmission): closed form (Proposition 1).
 * P4 (cooperative transmission, fixed OPV prefix): log-barrier damped-Newton
   interior-point method, branch-free with a fixed iteration budget so it can
-  be jit'ed and vmapped over all (SOV, prefix) candidates. This replaces the
+  be jit'ed and vmapped over all (SOV, prefix) candidates. Each Newton step
+  solves the diagonal-plus-rank-2 Hessian in closed form (elementwise work,
+  no factorization; `_solve_diag_rank2`). This replaces the
   paper's CVX call — same convex program, TPU-native solver (see DESIGN.md §3).
 
 The P4 solver supports a *warm start* (`p_init` + `warm_iters`): streaming
@@ -60,6 +62,91 @@ def _phi_grad_hess(p, a, q, cw, d, p_max, mu):
     grad = gF + g_lo + g_hi + g_c
     hess = HF + jnp.diag(H_lo + H_hi) + H_c
     return grad, hess
+
+
+def _phi_grad_parts(p, a, q, cw, d, p_max, mu):
+    """`_phi_grad_hess` in parts, by the same expressions. With
+    u = sqrt(cw)/s * a and v = sqrt(mu)/slack * d,
+        grad  = g_box + sqrt(cw) * u - sqrt(mu) * v,
+        -hess = diag(lam) + u u^T + v v^T,
+    where g_box = -q + mu/p - mu/(p_max - p) and lam = mu/p^2 +
+    mu/(p_max - p)^2 are the box barriers' (P4's cw >= 0, so -hess is
+    SPD). Returns (g_box, lam, u, v, sqrt(cw), -sqrt(mu)). The gradient's
+    a and d terms stay apart so that the Newton solve takes them through
+    u and v exactly: near the decodability boundary mu/slack reaches 1e11
+    and more, and the rounding of their sum would swamp the step."""
+    s = 1.0 + jnp.dot(a, p)
+    lo = jnp.maximum(p, 1e-12)
+    hi = jnp.maximum(p_max - p, 1e-12)
+    slack = jnp.maximum(-jnp.dot(d, p), 1e-12)
+    g_box = -q + mu / lo - mu / hi
+    lam = mu / lo ** 2 + mu / hi ** 2
+    root_cw, root_mu = jnp.sqrt(cw), jnp.sqrt(mu)
+    return (g_box, lam, root_cw / s * a, root_mu / slack * d, root_cw,
+            -root_mu)
+
+
+def _solve_diag_rank2(g, lam, u, v, cu, cv):
+    """Solve (diag(lam) + u u^T + v v^T) x = g + cu * u + cv * v for
+    lam > 0, in closed form.
+
+    In scaled coordinates (r = lam^-1/2, u~ = u r, v~ = v r) the matrix
+    is A = I + u~u~^T + v~v~^T and x = r * A^-1 (r g + cu u~ + cv v~).
+    Gram-Schmidt (twice, so the basis stays orthogonal when u~ and v~ are
+    nearly parallel, as P4's a and d are) gives an orthonormal e1, e2 with
+    u~ = t_u e1 and v~ = t_v (c e1 + n e2). In that basis the rank-2 part
+    is S = R R^T, R = [[t_u, t_v c], [0, t_v n]], so with K = I + S
+        A^-1 y = y - E (S + det(S) I) E^T y / det(K),
+        A^-1 u~ = t_u E [1 + t_v^2 n^2, -t_v^2 c n] / det(K),
+        A^-1 v~ = t_v E [c, n (1 + t_u^2)] / det(K),
+        det(K) = 1 + t_u^2 + t_v^2 (c^2 + n^2) + t_u^2 t_v^2 n^2,
+    a sum of positive terms, and det(S) = (t_u t_v n)^2 the Gram
+    determinant, as a product: nothing cancels when u~ and v~ are nearly
+    parallel. Every coefficient is divided by (1 + t_u^2)(1 + t_v^2), so
+    nothing overflows float32 when lam spans many decades or a barrier's
+    slack is at its 1e-12 floor. Elementwise ops and sums over the last
+    axis only (no factorization, no matmul, no n x n intermediate).
+    """
+    r = jax.lax.rsqrt(lam)
+
+    def unit(w):
+        m = jnp.max(jnp.abs(w))
+        return w / jnp.where(m > 0, m, 1.0), m
+
+    def ratios(t2):
+        # (1/(1 + t2), t2/(1 + t2)); t2 = 0 gives (1, 0), t2 = inf (0, 1)
+        return 1.0 / (1.0 + t2), 1.0 / (1.0 + 1.0 / t2)
+
+    uh, m_u = unit(u * r)
+    vh, m_v = unit(v * r)
+    nu = jnp.sqrt(jnp.sum(uh * uh))
+    e1 = uh / jnp.where(nu > 0, nu, 1.0)
+    c = jnp.sum(vh * e1)
+    vp = vh - c * e1
+    c2 = jnp.sum(vp * e1)
+    vp, c = vp - c2 * e1, c + c2
+    n = jnp.sqrt(jnp.sum(vp * vp))
+    e2 = vp / jnp.where(n > 0, n, 1.0)
+    tu = m_u * nu
+    ru, wu = ratios(tu * tu)
+    rv, wv = ratios(m_v * m_v)
+    det = rv + ru * wv * (c * c + n * n) + wu * wv * n * n
+    gs = g * r
+    g1, g2 = jnp.sum(e1 * gs), jnp.sum(e2 * gs)
+    su, sv = cu * tu * ru, cv * m_v * rv
+    y1 = (su * (rv + wv * n * n) + sv * ru * c
+          - (wu * rv + ru * wv * c * c + wu * wv * n * n) * g1
+          - ru * wv * c * n * g2)
+    y2 = (sv * n - su * wv * c * n - ru * wv * c * n * g1
+          - wv * n * n * g2)
+    return (gs + (y1 * e1 + y2 * e2) / det) * r
+
+
+def _newton_direction(p, a, q, cw, d, p_max, mu):
+    """The damped Newton direction of phi at p: x with
+    (1e-9 I - hess) x = grad, from the parts of both."""
+    g_box, lam, u, v, cu, cv = _phi_grad_parts(p, a, q, cw, d, p_max, mu)
+    return _solve_diag_rank2(g_box, lam + 1e-9, u, v, cu, cv)
 
 
 def _project_feasible(p, d, p_max, margin=0.999):
@@ -155,10 +242,8 @@ def solve_p4(cw: jax.Array, a: jax.Array, q: jax.Array, d: jax.Array,
 
     def step(p, x):
         mu, i = x
-        grad, hess = _phi_grad_hess(p, a, q, cw, d, p_max, mu)
         # damped Newton ascent on the concave barrier objective
-        hess = hess - 1e-9 * jnp.eye(n)
-        dlt = jnp.linalg.solve(hess, -grad)
+        dlt = _newton_direction(p, a, q, cw, d, p_max, mu)
         # keep steps inside the trust region of the barrier
         norm = jnp.linalg.norm(dlt)
         dlt = dlt * jnp.minimum(1.0, 0.5 * jnp.max(p_max) / (norm + 1e-12))

@@ -131,10 +131,12 @@ def test_repeat_session_rides_warm_p4():
     bit-for-bit lossless (re-packing the unpacked sessions reproduces
     the dispatch's packed fleet exactly), and a second request's
     responses are bit-for-bit the solo B=1 warm run. The table itself is
-    only compared to the B=1 run at tolerance: XLA batches the IPM's
-    linear solves differently at B=2 vs B=1 and Newton amplifies the
-    last-ulp difference — the response-level contract is what stays
-    bitwise. Tiny shapes keep the VEDS compile quick-lane affordable."""
+    only compared to the B=1 run at tolerance: a batched lowering may sum
+    the Newton step's dot products in another order at B=2 than at B=1,
+    and Newton amplifies the last-ulp difference — the response-level
+    contract is what stays bitwise. (On the CPU at these shapes the
+    table comes out bitwise equal as well.) Tiny shapes keep the VEDS
+    compile quick-lane affordable."""
     from repro.core.streaming import pack_cells
     kw = dict(max_rounds=2, scheduler="veds", n_sov=3, n_opv=2,
               n_slots=6, ipm_iters=4, ipm_warm_iters=2)

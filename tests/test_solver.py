@@ -7,13 +7,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.solver import dt_power_opt, p4_seed_table, solve_p4
+from repro.core import solver
+from repro.core.solver import (_project_feasible, dt_power_opt,
+                               p4_seed_table, solve_p4)
 
 try:
     from hypothesis import given, settings, strategies as st
     HAS_HYPOTHESIS = True
 except ImportError:                       # dev extra; CI installs it
     HAS_HYPOTHESIS = False
+
+try:
+    import mpmath
+    HAS_MPMATH = True
+except ImportError:                       # dev extra; CI installs it
+    HAS_MPMATH = False
 
 try:
     from scipy.optimize import minimize
@@ -298,8 +306,8 @@ def test_p4_adaptive_splits_tiers_and_stays_feasible():
     np.testing.assert_array_equal(np.asarray(p_far), np.asarray(p_full))
 
     # vmapped over the two seeds in one call: tier routing is per-lane.
-    # fp32-close, not bitwise — vmap lowers the Newton linalg.solve as
-    # a batched factorization with a different op order
+    # fp32-close, not bitwise — vmap lowers the step's dot products as
+    # batched contractions that sum in another order
     seeds = jnp.stack([p_c, bad])
     pv, _ = jax.vmap(
         lambda s: solve_p4(*args, iters=16, p_init=s, warm_iters=4,
@@ -312,6 +320,224 @@ def test_p4_adaptive_splits_tiers_and_stays_feasible():
         assert np.isfinite(p).all()
         assert (p >= -1e-6).all() and (p <= 0.3 + 1e-6).all()
         assert d @ p <= 1e-5
+
+
+# ---- the structured Newton solve (DESIGN.md §3) -------------------------
+
+N_NEWTON = 11                     # 1 + U at the paper's U = 10
+
+
+def _newton_states(rng, count, *, gap=(0.0, 0.3), margin=(0.1, 0.9),
+                   cw_zero=False, n_sched=None, at_pmax=False):
+    """`count` Newton states (p, a, q, cw, d, p_max, mu) of P4 instances
+    shaped like `_rand_instance` (d = a - g_min e0, unscheduled OPVs at
+    a = 0, p = 1e-9), with p projected feasible: `gap` is the log10 range
+    of g_min / a0 - 1 (tiny: a and d nearly parallel), `margin` the
+    projection's (1: d.p on the decodability boundary, the slack at its
+    1e-12 floor), `n_sched` the scheduled OPVs (default: random), and
+    `at_pmax` puts the SOV and an unscheduled OPV at p_max - 1e-9."""
+    n = N_NEWTON
+    out = []
+    for _ in range(count):
+        a = np.abs(rng.normal(0, 5, n))
+        k = rng.integers(1, n) if n_sched is None else n_sched
+        a[1 + k:] = 0.0
+        a[0] = abs(rng.normal(0, 5)) + 0.1
+        q = np.abs(rng.normal(0, 0.1, n)) + 1e-3
+        d = a.copy()
+        d[0] = -a[0] * 10.0 ** rng.uniform(*gap)
+        pmax = np.full(n, 0.3)
+        cw = 0.0 if cw_zero else abs(rng.normal(0.5, 0.5)) + 0.01
+        p = rng.uniform(1e-3, 0.299, n)
+        p[a == 0] = 1e-9
+        if at_pmax:
+            p[0] = p[-1] = 0.3 - 1e-9
+        p = np.asarray(_project_feasible(
+            jnp.asarray(p, jnp.float32), jnp.asarray(d, jnp.float32),
+            jnp.asarray(pmax, jnp.float32), margin=rng.uniform(*margin)))
+        out.append((p, a, q, cw, d, pmax, 10.0 ** rng.uniform(-3, -1)))
+    return [np.stack(x).astype(np.float32) for x in zip(*out)]
+
+
+def _veds_states(monkeypatch):
+    """Every Newton state of one slot of a VEDS round at S = U = 10: the
+    100 COT candidates x 25 steps, recorded as `_cot_candidates` runs."""
+    from repro.channel.mobility import ManhattanParams
+    from repro.channel.v2x import ChannelParams
+    from repro.core import veds
+    from repro.core.lyapunov import VedsParams
+    from repro.core.scenario import ScenarioParams, make_round
+    ch, prm = ChannelParams(), VedsParams(alpha=2.0, V=0.2, Q=1e7, slot=0.1)
+    rnd = make_round(jax.random.key(0),
+                     ScenarioParams(n_sov=10, n_opv=10, n_slots=2),
+                     ManhattanParams(v_max=10.0), ch, prm)
+    seen, direction = [], solver._newton_direction
+
+    def record(*state):
+        jax.debug.callback(lambda *x: seen.append(x), *state)
+        return direction(*state)
+
+    monkeypatch.setattr(solver, "_newton_direction", record)
+    rng = np.random.default_rng(0)
+    w, qs, qu = (jnp.asarray(rng.uniform(lo, hi, 10), jnp.float32)
+                 for lo, hi in ((0.2, 1.0), (0.0, 2.0), (0.0, 2.0)))
+    jax.block_until_ready(veds._cot_candidates(
+        w, qs, qu, rnd.g_sr[1], rnd.g_or[1], rnd.g_so[1],
+        jnp.ones(10, bool), prm, ch))
+    assert len(seen) == 100 * prm.ipm_iters
+    return [np.stack(x) for x in zip(*seen)]
+
+
+NEWTON_FAMILIES = {
+    "random": lambda rng, mp: _newton_states(rng, 400),
+    "nearly_parallel": lambda rng, mp: _newton_states(rng, 300,
+                                                      gap=(-6.0, -2.0)),
+    "slack_floor": lambda rng, mp: _newton_states(rng, 300, gap=(-5.0, -1.0),
+                                                  margin=(1.0, 1.0)),
+    "cw_zero": lambda rng, mp: _newton_states(rng, 300, cw_zero=True),
+    "unscheduled": lambda rng, mp: _newton_states(rng, 300, n_sched=1),
+    "at_pmax": lambda rng, mp: _newton_states(rng, 300, at_pmax=True),
+    "veds_round": lambda rng, mp: [x[::8] for x in _veds_states(mp)],
+}
+
+
+def _exact_solve(g, lam, u, v, cu, cv):
+    """Solve each (diag(lam) + uu^T + vv^T) x = g + cu u + cv v exactly
+    enough to judge float32 solves: 60-digit LU on the float32 parts. (A
+    float64 solve is no reference here: at the slack floor vv^T swamps
+    diag(lam) by over 16 decades, and the float64 matrix is singular.)"""
+    out = []
+    with mpmath.workdps(60):
+        for g, lam, u, v, cu, cv in zip(*(np.asarray(x, np.float64).tolist()
+                                          for x in (g, lam, u, v, cu, cv))):
+            u, v = mpmath.matrix(u), mpmath.matrix(v)
+            m = mpmath.diag(lam) + u * u.T + v * v.T
+            rhs = mpmath.matrix(g) + mpmath.mpf(cu) * u + mpmath.mpf(cv) * v
+            out.append([float(x) for x in mpmath.lu_solve(m, rhs)])
+    return np.array(out)
+
+
+def _dense_parts(states):
+    """The Newton system's float32 parts at each state, and the dense
+    matrix and right-hand side the LU solve took, in float32."""
+    g, lam, u, v, cu, cv = jax.vmap(solver._phi_grad_parts)(*states)
+    lam = lam + jnp.float32(1e-9)
+    dense = jax.vmap(lambda lam, u, v: jnp.diag(lam) + jnp.outer(u, u)
+                     + jnp.outer(v, v))(lam, u, v)
+    rhs = g + cu[:, None] * u + cv[:, None] * v
+    return (g, lam, u, v, cu, cv), dense, rhs
+
+
+@pytest.mark.skipif(not HAS_MPMATH, reason="dev extra; pip install -r "
+                    "requirements-dev.txt")
+@pytest.mark.parametrize("family", sorted(NEWTON_FAMILIES))
+def test_newton_direction_matches_exact(family, monkeypatch):
+    """The closed-form Newton solve against an exact solve of the same
+    float32 parts: its median and 99th-percentile relative errors are
+    within 10x of float32 `jnp.linalg.solve`'s on the dense system built
+    from those parts, and it is always finite (the matrix is SPD)."""
+    states = [jnp.asarray(x) for x in
+              NEWTON_FAMILIES[family](np.random.default_rng(21), monkeypatch)]
+    x = jax.vmap(solver._newton_direction)(*states)
+    parts, dense, rhs = _dense_parts(states)
+    x_lu = jnp.linalg.solve(dense, rhs[..., None])[..., 0]
+    exact = _exact_solve(*parts)
+
+    def rel_err(y):
+        y = np.asarray(y, np.float64)
+        return (np.linalg.norm(y - exact, axis=-1)
+                / np.linalg.norm(exact, axis=-1))
+
+    assert np.isfinite(np.asarray(x)).all()
+    e = rel_err(x)
+    e_lu = np.nan_to_num(rel_err(x_lu), nan=np.inf)   # LU overflows there
+    for qq in (50, 99):
+        ours, lu = (np.percentile(v, qq, method="higher") for v in (e, e_lu))
+        assert ours <= 10 * lu, (qq, ours, lu)
+
+
+def test_phi_grad_parts_rebuild_the_definition():
+    """The parts the Newton step solves with are `_phi_grad_hess`'s:
+    g_box + sqrt(cw) u - sqrt(mu) v is its gradient and diag(lam) + uu^T
+    + vv^T its -hess, each to float32 rounding of its terms."""
+    states = [jnp.asarray(x) for x in
+              _newton_states(np.random.default_rng(23), 500)]
+    g, lam, u, v, cu, cv = jax.vmap(solver._phi_grad_parts)(*states)
+    grad, hess = jax.vmap(solver._phi_grad_hess)(*states)
+    g, lam, u, v, cu, cv, grad, hess = (
+        np.asarray(x, np.float64)
+        for x in (g, lam, u, v, cu, cv, grad, hess))
+    terms = (g, cu[:, None] * u, cv[:, None] * v)
+    assert (np.abs(sum(terms) - grad)
+            <= 1e-6 * sum(np.abs(t) for t in terms)).all()
+    outer = lambda x: x[:, :, None] * x[:, None, :]  # noqa: E731
+    eye = lam[:, :, None] * np.eye(N_NEWTON)
+    assert (np.abs(eye + outer(u) + outer(v) + hess)
+            <= 1e-6 * (eye + np.abs(outer(u)) + np.abs(outer(v)))).all()
+
+
+def _lu_direction(p, a, q, cw, d, p_max, mu):
+    """The Newton direction as solved before the closed form: a dense
+    LU solve of the definition's Hessian."""
+    grad, hess = solver._phi_grad_hess(p, a, q, cw, d, p_max, mu)
+    return jnp.linalg.solve(hess - 1e-9 * jnp.eye(a.shape[0]), -grad)
+
+
+def test_solve_p4_has_no_factorization():
+    """The vmapped cold, warm and two-tier solves lower to no LU,
+    triangular solve or Cholesky: the Newton step is elementwise work."""
+    rng = np.random.default_rng(22)
+    a, q, d, pmax, cw = (np.stack(x).astype(np.float32) for x in zip(
+        *[_rand_instance(rng, N_NEWTON) for _ in range(4)]))
+    args = (jnp.asarray(cw), jnp.asarray(a), jnp.asarray(q), jnp.asarray(d),
+            jnp.asarray(pmax))
+    seed = p4_seed_table((4, N_NEWTON), 0.3)
+    kws = ({}, {"warm_iters": 8},
+           {"warm_iters": 4, "far_iters": 12, "far_grad_tol": 1.0})
+
+    def prims(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from prims(sub)
+
+    for kw in kws:
+        p_init = seed if kw else None
+        closed = jax.make_jaxpr(jax.vmap(
+            lambda c, a_, q_, d_, pm, p0: solve_p4(
+                c, a_, q_, d_, pm, iters=12, p_init=p0, **kw),
+            in_axes=(0, 0, 0, 0, 0, 0 if kw else None)))(*args, p_init)
+        names = set(prims(closed.jaxpr))
+        assert "scan" in names and "sqrt" in names, names
+        assert not names & {"lu", "triangular_solve", "cholesky"}, names
+
+
+def test_p4_closed_form_matches_lu_solve(monkeypatch):
+    """On the `test_p4_vs_scipy` instances the closed-form Newton solve
+    gives the values of the LU solve it replaced to rtol 1e-4, and p to
+    the rtol 1e-3 / atol 1e-8 of a reordered solve."""
+    rng = np.random.default_rng(1)
+    insts = []
+    for _ in range(25):
+        n = 1 + rng.integers(1, 8)
+        insts.append(_rand_instance(rng, n))
+        for _ in range(3):                   # scipy's draws in that test
+            rng.random(n)
+
+    def solve_all():
+        return [solve_p4(jnp.float32(cw), jnp.asarray(a, jnp.float32),
+                         jnp.asarray(q, jnp.float32),
+                         jnp.asarray(d, jnp.float32),
+                         jnp.asarray(pmax, jnp.float32))
+                for a, q, d, pmax, cw in insts]
+
+    new = solve_all()
+    monkeypatch.setattr(solver, "_newton_direction", _lu_direction)
+    old = solve_all()
+    for (p_n, v_n), (p_o, v_o) in zip(new, old):
+        np.testing.assert_allclose(float(v_n), float(v_o), rtol=1e-4)
+        np.testing.assert_allclose(np.asarray(p_n), np.asarray(p_o),
+                                   rtol=1e-3, atol=1e-8)
 
 
 if HAS_HYPOTHESIS:
