@@ -51,6 +51,7 @@ def main(argv=None) -> int:
     cfg = reg.config(work["config"])
     traffic = reg.traffic(work["traffic"])
     driver = reg.driver(traffic["driver"])
+    model = reg.model(cfg["model"]["name"])
     seeds = [int(s) for s in args.seeds.split(",")]
     runs = [(s, "", i < args.control) for i, s in enumerate(seeds)]
     for f in args.fault:
@@ -60,7 +61,7 @@ def main(argv=None) -> int:
     try:
         for seed, fault, control in runs:
             t0 = time.perf_counter()
-            cell = driver.build(cfg, traffic, seed, fault)
+            cell = driver.build(cfg, model, traffic, seed, fault)
             cell.setup()
             if args.seconds:
                 cell.window(args.seconds)

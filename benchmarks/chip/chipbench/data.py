@@ -1,11 +1,12 @@
-"""Inputs made from the seed, on the device: weights, client data and
-the per-round draws. The program receives them; the reference gets the
-same arrays, so neither takes anything the other made."""
+"""Draws made from the seed, on the device, whatever the model: the
+root key and the per-round and per-request draws. The weights and the
+client data come from the configuration's model file
+(`models/<model>.py`). The program receives them; the reference gets
+the same arrays, so neither takes anything the other made."""
 from __future__ import annotations
 
 import functools
-import math
-from typing import Dict, Sequence
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -17,46 +18,6 @@ def root_key(seed: int) -> jax.Array:
     if seed < 0:
         raise ValueError(f"seed {seed} is negative")
     return jax.random.fold_in(jax.random.key(seed & 0xFFFFFFFF), seed >> 32)
-
-
-@functools.partial(jax.jit, static_argnames=("channels", "classes",
-                                             "flat"))
-def cnn_weights(key, channels: Sequence[int], classes: int, flat: int):
-    """The six-conv CNN's weights in its parameter layout: He-normal 3x3
-    kernels over the true fan-in, zero biases, a head drawn from a
-    normal truncated at 2 sigma and scaled by 1/sqrt(fan-in)."""
-    ks = jax.random.split(key, len(channels) + 1)
-    convs, cin = [], 3
-    for k, cout in zip(ks, channels):
-        std = math.sqrt(2.0 / (9 * cin))
-        convs.append({"w": std * jax.random.normal(k, (3, 3, cin, cout)),
-                      "b": jnp.zeros((cout,))})
-        cin = cout
-    head = jax.random.truncated_normal(ks[-1], -2.0, 2.0, (flat, classes))
-    return {"convs": convs,
-            "head": {"w": head / math.sqrt(flat), "b": jnp.zeros((classes,))}}
-
-
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
-def _client_shards(key, n_clients: int, n_per: int, classes: int,
-                   per_client: int, image: Sequence[int]):
-    k_proto, k_noise = jax.random.split(key)
-    protos = jax.random.normal(k_proto, (classes,) + tuple(image))
-    c = jnp.arange(n_clients)[:, None]
-    part = jnp.arange(n_per)[None, :] * per_client // n_per
-    y = ((c * per_client + part) % classes).astype(jnp.int32)
-    x = protos[y] + 0.6 * jax.random.normal(
-        k_noise, (n_clients, n_per) + tuple(image))
-    return {"x": x, "y": y}, jnp.full((n_clients,), n_per, jnp.int32)
-
-
-def client_shards(key, n_clients: int, n_per: int, classes: int,
-                  per_client: int, image: Sequence[int]):
-    """Non-IID image shards: client c holds `per_client` classes, equal
-    parts of each; an image is its class prototype plus 0.6 x N(0, 1)
-    noise. Returns ({"x": [C, n, H, W, 3], "y": [C, n]}, n_samples)."""
-    return _client_shards(key, n_clients, n_per, classes, per_client,
-                          tuple(image))
 
 
 @functools.partial(jax.jit, static_argnames=("L", "B", "S", "per_cell",

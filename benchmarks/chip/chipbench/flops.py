@@ -1,7 +1,8 @@
-"""Operations and bytes of the timed work, counted from shapes."""
+"""Operations and bytes of the timed work, counted from shapes. A
+model's own count comes from its model file (`models/<model>.py`)."""
 from __future__ import annotations
 
-from typing import Dict, Sequence
+from typing import Dict
 
 # The DT-score kernel, per candidate: the closed-form power (divide,
 # reciprocal, two clips, a multiply-subtract), the rate (a log1p and
@@ -12,29 +13,12 @@ LANES = 128
 BLOCK_ROWS = 8
 
 
-def cnn_forward_flops(channels: Sequence[int], image: Sequence[int],
-                      classes: int, kernel: int = 3) -> int:
-    """Multiply-adds x 2 of one image through the six 3x3 SAME convs
-    (2x2 pooling after every pair) and the linear head."""
-    h, w, cin = image
-    total = 0
-    for i, cout in enumerate(channels):
-        total += 2 * h * w * kernel * kernel * cin * cout
-        cin = cout
-        if i % 2 == 1:
-            h, w = h // 2, w // 2
-    return total + 2 * h * w * cin * classes
-
-
-def cnn_train_flops(cfg: Dict) -> int:
-    """Forward and backward of one image: three times the forward."""
-    m = cfg["model"]
-    return 3 * cnn_forward_flops(m["channels"], m["image"], m["classes"])
-
-
-def cell_round_flops(cfg: Dict) -> int:
-    """One cell-round trains S clients on a minibatch each."""
-    return cfg["n_sov"] * cfg["batch_size"] * cnn_train_flops(cfg)
+def cell_round_flops(cfg: Dict, model) -> int:
+    """One cell-round trains S clients on a minibatch each; `model` is
+    the configuration's model file, which counts a sample's forward and
+    backward."""
+    return cfg["n_sov"] * cfg["batch_size"] * \
+        model.train_flops_per_sample(cfg["model"])
 
 
 def veds_score_tiles(n_candidates: int) -> int:

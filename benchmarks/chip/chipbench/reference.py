@@ -11,8 +11,9 @@ and the configuration file alone, what one round of the system does:
   scheduler  VEDS+COT (Algorithms 1 and 2, P4 by an interior point) or
              MADCA (best instantaneous V2I channel, direct uploads only)
   queues     virtual energy queues, eqs. (19)-(20), carried per vehicle
-  training   one local SGD step per SOV on its minibatch of the six-conv
-             CNN, mask-weighted FedAvg of the gradients, clipped at 5
+  training   one local SGD step per SOV on its minibatch of the
+             configuration's model (the `reference_loss` of its model
+             file), mask-weighted FedAvg of the gradients, clipped at 5
 
 It imports nothing of the program and is written for clarity: one cell
 at a time, one compiled program per cell-round, float32 at the highest
@@ -387,39 +388,16 @@ def madca_slot(c: Dict, rnd: Dict, t, zeta, qs, qu, left):
 
 # -------------------------------------------------------------- training
 
-def cnn_logits(params, x):
-    """Six 3x3 SAME convs with ReLU, 2x2 max-pool after every pair, and
-    a linear head, at the highest matmul precision."""
-    for i, layer in enumerate(params["convs"]):
-        x = jax.lax.conv_general_dilated(
-            x, layer["w"], (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            precision=HIGHEST) + layer["b"]
-        x = jax.nn.relu(x)
-        if i % 2 == 1:
-            x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                                      (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-    x = x.reshape(x.shape[0], -1)
-    return jnp.dot(x, params["head"]["w"], precision=HIGHEST) \
-        + params["head"]["b"]
-
-
-def cnn_loss(params, x, y):
-    logits = cnn_logits(params, x).astype(jnp.float32)
-    return jnp.mean(jax.nn.logsumexp(logits, -1)
-                    - jnp.take_along_axis(logits, y[:, None], -1)[:, 0])
-
-
-def fedavg_step(params, x, y, weights, c: Dict):
-    """Every client's loss and gradient on its minibatch (`x [S, bs, ...]`,
-    `y [S, bs]`), their mean weighted by `weights` (uploaded x sample
-    count; a client of weight 0 adds nothing), clipped to global norm
-    `clip`, and one SGD step. Returns (params, round loss), the loss
-    weighted alike. A round in which no upload succeeded keeps the
-    weights and reports the loss 0, as the system defines that round's
-    loss. Sums are elementwise in float32."""
-    losses, grads = jax.vmap(jax.value_and_grad(cnn_loss),
-                             in_axes=(None, 0, 0))(params, x, y)
+def fedavg_step(loss_fn, params, batch, weights, c: Dict):
+    """Every client's `loss_fn` and gradient on its minibatch (`batch` a
+    dict of [S, bs, ...] leaves), their mean weighted by `weights`
+    (uploaded x sample count; a client of weight 0 adds nothing),
+    clipped to global norm `clip`, and one SGD step. Returns (params,
+    round loss), the loss weighted alike. A round in which no upload
+    succeeded keeps the weights and reports the loss 0, as the system
+    defines that round's loss. Sums are elementwise in float32."""
+    losses, grads = jax.vmap(jax.value_and_grad(loss_fn),
+                             in_axes=(None, 0))(params, batch)
     total = jnp.sum(weights)
     ok = total > 0
     den = jnp.where(ok, total, 1.0)
@@ -479,12 +457,13 @@ def schedule(c: Dict, rnd: Dict, qs, qu):
     return (zeta >= c["Q_bits"]) & valid, e_s + e_cp, e_o, qs, qu
 
 
-def cell_round(c: Dict, dtype, key, cell: Dict, active, params, data,
-               n_samples, sel, u):
+def cell_round(c: Dict, dtype, loss_fn, key, cell: Dict, active, params,
+               data, n_samples, sel, u):
     """One round of one cell: drive its vehicles and draw their channels,
     schedule the T slots, carry the queues and batteries of the vehicles
-    that played, and train the cell's model on the clients `sel` (sample
-    index = floor(u x n), capped at n - 1), weighted by who uploaded.
+    that played, and train the cell's model (`loss_fn`) on the clients
+    `sel` (sample index = floor(u x n), capped at n - 1), weighted by who
+    uploaded; every leaf of `data` is gathered alike.
     `cell` holds the cell's rows of the fleet. Returns (the cell's new
     fleet rows, the round's outputs, new params, round loss)."""
     f32 = lambda x: x.astype(jnp.float32)                    # noqa: E731
@@ -503,9 +482,9 @@ def cell_round(c: Dict, dtype, key, cell: Dict, active, params, data,
     n = n_samples[sel]
     idx = jnp.minimum((u.astype(jnp.float32) * f32(n)[:, None])
                       .astype(jnp.int32), jnp.maximum(n - 1, 0)[:, None])
-    x = data["x"][sel[:, None], idx].astype(dtype)
-    y = data["y"][sel[:, None], idx]
-    new_params, loss = fedavg_step(params, x, y, f32(succ) * f32(n), c)
+    batch = cast(jax.tree.map(lambda a: a[sel[:, None], idx], data), dtype)
+    new_params, loss = fedavg_step(loss_fn, params, batch,
+                                   f32(succ) * f32(n), c)
     rows = {"pos": pos, "dir": heading, "covered": cov0, "queue": queue,
             "energy": jnp.maximum(energy, 0.0)}
     outs = {"success": succ, "energy_sov": f32(e_s), "energy_opv": f32(e_o),
@@ -515,18 +494,18 @@ def cell_round(c: Dict, dtype, key, cell: Dict, active, params, data,
 
 class Reference:
     """The reference for one configuration: one compiled program per
-    cell-round. `dtype` is the compute dtype (float32; bfloat16 is the
-    control)."""
+    cell-round. `loss_fn` is the model file's `reference_loss`, `dtype`
+    the compute dtype (float32; bfloat16 is the control)."""
 
-    def __init__(self, c: Dict, dtype=jnp.float32):
+    def __init__(self, c: Dict, loss_fn, dtype=jnp.float32):
         self.c, self.dtype = c, dtype
-        self._round = jax.jit(lambda *a: cell_round(c, dtype, *a))
+        self._round = jax.jit(lambda *a: cell_round(c, dtype, loss_fn, *a))
 
     def run(self, key, round_keys, params, shards, n_samples, sel, mb_u,
             n_rounds: int, B: int, handoff_on: bool) -> Dict:
         """`n_rounds` rounds of B cells. `key` seeds the fleet,
         `round_keys[r]` round r; `params` are the initial weights of
-        every cell, `shards` the client data ({"x", "y"} of [C, n, ...]),
+        every cell, `shards` the client data (a dict of [C, n, ...] leaves),
         `sel [R, B, S]` and `mb_u [R, B, S, bs]` the harness's draws.
         Returns per-round [R, B, ...] masks, energies, queues and losses,
         every cell's weights after each round, and the final fleet."""

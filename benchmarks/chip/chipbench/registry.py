@@ -4,14 +4,23 @@
 and metrics. Everything that belongs to one of them sits in a file of
 its own under the benchmark's directory, found by the name:
 
-  configs/<config>.json      the configuration as it is run
+  configs/<config>.json      the configuration as it is run; its
+                             `model.name` names the model file
+  models/<model>.py          one model: `weights(key, m)`,
+                             `shards(key, n_clients, traffic, m)`,
+                             `program_loss(m)`, `reference_loss(params,
+                             batch)` and `train_flops_per_sample(m)`,
+                             `m` the configuration's `model` group
   traffic/<traffic>.json     a traffic mix: parameters for a driver
   drivers/<driver>.py        the code that drives one kind of traffic
   metrics/<metric>.py        the reader of one per-layer metric
   limits/<workload>.json     the limit of each number `correct` compares
 
-so adding a cell, a configuration or a metric adds files and entries
-and edits nothing that is there.
+so adding a cell, a configuration, a model or a metric adds files and
+entries and edits nothing that is there. A new model brings
+`models/<model>.py`, `configs/<config>.json`, `traffic/<traffic>.json`
+and `limits/<cell>.json`; a new metric brings `metrics/<metric>.py`;
+only a new kind of traffic brings a driver.
 """
 from __future__ import annotations
 
@@ -43,7 +52,8 @@ def load_module(path: Path, prefix: str) -> ModuleType:
 
 class Registry:
     """A view of one checkout's benchmark. `root` holds BENCHMARK.json,
-    `bench_dir` the configs/, traffic/, drivers/ and metrics/ files."""
+    `bench_dir` the configs/, models/, traffic/, drivers/ and metrics/
+    files."""
 
     def __init__(self, root: Path = CHECKOUT, bench_dir: Path = BENCH_DIR):
         self.root, self.bench_dir = Path(root), Path(bench_dir)
@@ -75,6 +85,10 @@ class Registry:
     def driver(self, name: str) -> ModuleType:
         return load_module(self.bench_dir / "drivers" / f"{name}.py",
                            "chipbench_driver_")
+
+    def model(self, name: str) -> ModuleType:
+        return load_module(self.bench_dir / "models" / f"{name}.py",
+                           "chipbench_model_")
 
     def metrics_of(self, workload: str, kind: str) -> List[Dict]:
         """The `end_to_end` or `per_layer` metrics this cell reports."""

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import bisect
 import glob
+import importlib
 import os
 import re
 import sys
@@ -41,8 +42,10 @@ def program_scopes() -> Optional[Tuple[Tuple[str, ...], Dict[str, str]]]:
     """(the program's layers, {instruction: layer}) over every program
     it registered, or None where it names no layers. An instruction that
     two programs put in different layers is left out."""
+    # import_module honours a None entry in sys.modules, which `from
+    # repro import obs` does not once the package holds the attribute
     try:
-        from repro import obs
+        obs = importlib.import_module("repro.obs")
     except ImportError:
         return None
     table: Dict[str, str] = {}

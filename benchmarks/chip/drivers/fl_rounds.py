@@ -3,11 +3,12 @@
 The traffic file gives the cells B, each cell's clients and their
 shards, whether the cells hand vehicles off to their neighbours, and
 how many rounds one dispatch of the fused engine runs. Set-up builds
-the weights and data from the seed, compiles the segment program and
-drives it through the first `checked_rounds` rounds through its own
-call (single rounds made active by the segment's round mask). The same
-carry then trains on, segment after segment, until the window's time is
-up. Every round's draws depend on (seed, round) alone.
+the weights and data from the seed with the configuration's model file
+(`models/<model>.py`), compiles the segment program and drives it
+through the first `checked_rounds` rounds through its own call (single
+rounds made active by the segment's round mask). The same carry then
+trains on, segment after segment, until the window's time is up.
+Every round's draws depend on (seed, round) alone.
 """
 from __future__ import annotations
 
@@ -34,9 +35,10 @@ class Cell:
     decision altered where it is produced) or "no_handoff" (the exchange
     of vehicles between cells left out)."""
 
-    def __init__(self, cfg: Dict, traffic: Dict, seed: int,
+    def __init__(self, cfg: Dict, model, traffic: Dict, seed: int,
                  fault: str = ""):
-        self.cfg, self.traffic, self.fault = cfg, traffic, fault
+        self.cfg, self.model, self.traffic, self.fault = cfg, model, \
+            traffic, fault
         self.B = int(traffic["cells"])
         self.L = int(traffic["segment_rounds"])
         self.R0 = int(traffic["checked_rounds"])
@@ -51,23 +53,20 @@ class Cell:
 
         from repro.core.streaming import StreamConfig
         from repro.fl.engine import ClientShards, fused_segment, init_carry
-        from repro.models.cnn import cnn_loss
         cfg, tr, B, L = self.cfg, self.traffic, self.B, self.L
-        m = cfg["model"]
+        m, model = cfg["model"], self.model
         with span("setup"):
-            self.p0 = D.cnn_weights(self.k_w, tuple(m["channels"]),
-                                    m["classes"], m["flat"])
-            shape = (self.k_data, B * tr["clients_per_cell"],
-                     tr["samples_per_client"], m["classes"],
-                     tr["classes_per_client"], tuple(m["image"]))
-            data, n = D.client_shards(*shape)
+            self.p0 = model.weights(self.k_w, m)
+            data, n = model.shards(self.k_data, B * tr["clients_per_cell"],
+                                   tr, m)
             self.shards = ClientShards(data=data, n_samples=n)
             sc, mob, ch, prm = program_params(cfg)
             scfg = StreamConfig(n_rounds=0, batch=B,
                                 carry_queues=cfg["carry_queues"],
                                 handoff=tr["handoff"])
-            loss = cnn_loss if self.fault != "half_batch" else \
-                half_batch_loss(cnn_loss)
+            loss = model.program_loss(m)
+            if self.fault == "half_batch":
+                loss = half_batch_loss(loss)
             run_cfg = scfg if self.fault != "no_handoff" else \
                 dataclasses.replace(scfg, handoff=False)
             self.seg = fused_segment(loss, cfg["scheduler"], sc, mob, ch,
@@ -164,12 +163,13 @@ class Cell:
                 self.shards.n_samples, sel, mb_u, R0, B,
                 self.traffic["handoff"])
         if self._ref is None:
-            self._ref = Reference(cfg).run(*args)
+            self._ref = Reference(cfg, self.model.reference_loss).run(*args)
             self._ref["params"] = [self._ref["params"][0],
                                    self._ref["params"][-1]]
         ref = self._ref
         if control:
-            prog = Reference(cfg, jnp.bfloat16).run(*args)
+            prog = Reference(cfg, self.model.reference_loss,
+                             jnp.bfloat16).run(*args)
             prog["params"] = [prog["params"][0], prog["params"][-1]]
         else:
             prog = {k: (np.stack(v) if k not in ("params", "fleet") else v)
@@ -184,6 +184,7 @@ class Cell:
                                 self.traffic["handoff"])
 
 
-def build(cfg: Dict, traffic: Dict, seed: int, fault: str = "") -> Cell:
-    return Cell(cfg, traffic, seed, fault)
+def build(cfg: Dict, model, traffic: Dict, seed: int,
+          fault: str = "") -> Cell:
+    return Cell(cfg, model, traffic, seed, fault)
 

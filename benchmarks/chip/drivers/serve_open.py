@@ -4,11 +4,12 @@ The traffic file gives the service's ladder (batch, horizon and
 occupancy tiers, batching window), the client data every session trains
 on, the sessions and their Zipf popularity, the round counts requests
 ask for, and the arrival rate. Set-up builds the weights and data from
-the seed, the service (`SchedulingService` behind a `BatchServer`),
-warms every tier the traffic reaches and creates every session. The
-window then sends each request when it is due (`chipbench.schedule`),
-whether or not earlier ones were answered, and times it from its due
-time to its response. A request that fails, or is still unanswered
+the seed with the configuration's model file (`models/<model>.py`), the
+service (`SchedulingService` behind a `BatchServer`), warms every tier
+the traffic reaches and creates every session. The window then sends
+each request when it is due (`chipbench.schedule`), whether or not
+earlier ones were answered, and times it from its due time to its
+response. A request that fails, or is still unanswered
 `drain_timeout_s` after the last was due, counts as missing.
 
 `correct` replays sampled sessions through the plain reference: every
@@ -50,10 +51,10 @@ class Cell:
     "flip" (one upload decision of the first answer of every dispatch
     altered where it is produced)."""
 
-    def __init__(self, cfg: Dict, traffic: Dict, seed: int,
+    def __init__(self, cfg: Dict, model, traffic: Dict, seed: int,
                  fault: str = ""):
-        self.cfg, self.traffic, self.seed, self.fault = cfg, traffic, seed, \
-            fault
+        self.cfg, self.model, self.traffic = cfg, model, traffic
+        self.seed, self.fault = seed, fault
         key = D.root_key(seed)
         self.k_w, self.k_data = (jax.random.fold_in(key, i) for i in (1, 2))
         self.svc_seed = int(np.random.default_rng([seed, 1]).integers(
@@ -95,18 +96,15 @@ class Cell:
     def setup(self) -> None:
         from repro.fl.engine import ClientShards
         from repro.launch.serve import SchedulingService
-        from repro.models.cnn import cnn_loss
         cfg, tr = self.cfg, self.traffic
-        m = cfg["model"]
+        m, model = cfg["model"], self.model
         with span("setup"):
-            self.p0 = D.cnn_weights(self.k_w, tuple(m["channels"]),
-                                    m["classes"], m["flat"])
-            data, n = D.client_shards(
-                self.k_data, tr["clients"], tr["samples_per_client"],
-                m["classes"], tr["classes_per_client"], tuple(m["image"]))
+            self.p0 = model.weights(self.k_w, m)
+            data, n = model.shards(self.k_data, tr["clients"], tr, m)
             self.shards = ClientShards(data=data, n_samples=n)
-            loss = cnn_loss if self.fault != "half_batch" else \
-                half_batch_loss(cnn_loss)
+            loss = model.program_loss(m)
+            if self.fault == "half_batch":
+                loss = half_batch_loss(loss)
             svc = SchedulingService(self._service_config(), params=self.p0,
                                     loss_fn=loss, client_data=self.shards)
             self._check_program(svc)
@@ -299,7 +297,8 @@ class Cell:
         refs = self.__dict__.setdefault("_refs", {})
         if (session, dtype) not in cache:
             if dtype not in refs:
-                refs[dtype] = Reference(self.cfg, dtype)
+                refs[dtype] = Reference(self.cfg, self.model.reference_loss,
+                                        dtype)
             res = refs[dtype].run(*args)
             cache[(session, dtype)] = {
                 "success": res["success"][:, 0], "loss": res["loss"][:, 0],
@@ -308,5 +307,6 @@ class Cell:
         return cache[(session, dtype)]
 
 
-def build(cfg: Dict, traffic: Dict, seed: int, fault: str = "") -> Cell:
-    return Cell(cfg, traffic, seed, fault)
+def build(cfg: Dict, model, traffic: Dict, seed: int,
+          fault: str = "") -> Cell:
+    return Cell(cfg, model, traffic, seed, fault)

@@ -63,7 +63,9 @@ def run_cell(reg, workload: str, seed: int, seconds: float, trace: bool,
     traffic = reg.traffic(work["traffic"])
     limits = reg.limits(workload)
     counter = CompileCounter()
-    cell = reg.driver(traffic["driver"]).build(cfg, traffic, seed, fault)
+    model = reg.model(cfg["model"]["name"])
+    cell = reg.driver(traffic["driver"]).build(cfg, model, traffic, seed,
+                                               fault)
     cell.setup()
     setup_s = time.perf_counter() - t_start
     counter.counting = True
@@ -91,8 +93,8 @@ def run_cell(reg, workload: str, seed: int, seconds: float, trace: bool,
         red = T.reduce(tr, window)
         device["busy_s"], device["window_s"] = red["busy_s"], red["window_s"]
         run = types.SimpleNamespace(
-            cfg=cfg, traffic=traffic, window=win, trace=red, raw=tr,
-            span=window, chips=len(devices),
+            cfg=cfg, model=model, traffic=traffic, window=win, trace=red,
+            raw=tr, span=window, chips=len(devices),
             peaks=peaks[devices[0].device_kind])
         for m in reg.metrics_of(workload, "per_layer"):
             v = reg.reader(m["name"]).read(run)

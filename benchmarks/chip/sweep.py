@@ -58,8 +58,9 @@ def main(argv=None) -> int:
     reg = Registry(CHECKOUT, BENCH_DIR)
     work = reg.workload(args.workload)
     traffic = reg.traffic(work["traffic"])
+    cfg = reg.config(work["config"])
     cell = reg.driver(traffic["driver"]).build(
-        reg.config(work["config"]), traffic, args.seed)
+        cfg, reg.model(cfg["model"]["name"]), traffic, args.seed)
     cell.setup()
     out = open(args.out, "a") if args.out else None
     try:

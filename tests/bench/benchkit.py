@@ -45,13 +45,13 @@ def real_spec():
 def tiny_bench(root: Path, workloads) -> Path:
     """A checkout under `root` holding the named cells of BENCHMARK.json
     at test size: their configurations, traffic and limits (at most
-    `CPU_GAP`), with the real drivers and metric readers. Returns the
-    benchmark directory."""
+    `CPU_GAP`), with the real drivers, model files and metric readers.
+    Returns the benchmark directory."""
     spec = real_spec()
     bench = root / "bench"
     for sub in ("configs", "traffic", "limits"):
         (bench / sub).mkdir(parents=True)
-    for sub in ("drivers", "metrics"):
+    for sub in ("drivers", "metrics", "models"):
         shutil.copytree(BENCH / sub, bench / sub)
     shutil.copy(BENCH / "peaks.json", bench / "peaks.json")
     works = [w for w in spec["workloads"] if w["name"] in workloads]

@@ -24,8 +24,9 @@ def test_the_bfloat16_control_is_not_correct(tiny, cell, seconds):
     reg = tiny(cell)
     w = reg.workload(cell)
     traffic = reg.traffic(w["traffic"])
-    c = reg.driver(traffic["driver"]).build(reg.config(w["config"]),
-                                            traffic, 2 ** 32 + 99)
+    cfg = reg.config(w["config"])
+    c = reg.driver(traffic["driver"]).build(
+        cfg, reg.model(cfg["model"]["name"]), traffic, 2 ** 32 + 99)
     c.setup()
     c.window(seconds)
     c.release()

@@ -136,7 +136,7 @@ def test_serve_pick_takes_short_sessions_and_the_most_served():
     driver = Registry(REPO, BENCH).driver("serve_open")
     traffic = {"check_history_rounds": 4, "check_rounds": 5,
                "check_long_rounds": 12}
-    cell = driver.build({}, traffic, 3)
+    cell = driver.build({}, None, traffic, 3)
     served = {"a": [8, 2], "b": [4, 4, 1], "c": [1, 1], "d": [2], "e": [1],
               "f": [4, 1]}
     cell.reqs = [{"session": s, "n_rounds": n}
@@ -159,15 +159,17 @@ def test_cnn_flops_match_the_hand_count():
     cfg = json.loads((BENCH / "configs" / "veds_cnn_paper.json")
                      .read_text())
     m = cfg["model"]
-    fwd = flops.cnn_forward_flops(m["channels"], m["image"], m["classes"])
+    cnn6 = Registry(REPO, BENCH).model(m["name"])
+    fwd = cnn6.forward_flops(m)
     convs = [2 * 32 * 32 * 9 * 3 * 32, 2 * 32 * 32 * 9 * 32 * 32,
              2 * 16 * 16 * 9 * 32 * 64, 2 * 16 * 16 * 9 * 64 * 64,
              2 * 8 * 8 * 9 * 64 * 128, 2 * 8 * 8 * 9 * 128 * 128]
     assert fwd == sum(convs) + 2 * 2048 * 10
     assert fwd == pytest.approx(77.3e6, rel=1e-3)
-    assert flops.cnn_train_flops(cfg) == 3 * fwd
+    assert cnn6.train_flops_per_sample(m) == 3 * fwd
     # one cell-round: S = 10 clients x a minibatch of 32
-    assert flops.cell_round_flops(cfg) == pytest.approx(74.2e9, rel=1e-3)
+    assert flops.cell_round_flops(cfg, cnn6) == pytest.approx(74.2e9,
+                                                              rel=1e-3)
 
 
 @pytest.mark.parametrize("n,tiles", [(10, 128), (128, 128), (129, 256),
@@ -185,10 +187,11 @@ def test_mfu_reader_counts_every_chip():
     reg = Registry(REPO, BENCH)
     cfg = json.loads((BENCH / "configs" / "madca_cnn_paper.json")
                      .read_text())
+    model = reg.model(cfg["model"]["name"])
     run = types.SimpleNamespace(
-        cfg=cfg, window={"cell_rounds": 100}, trace={"window_s": 2.0},
-        chips=4, peaks={"bf16_flops_per_s": 1e15})
-    want = 100 * flops.cell_round_flops(cfg) / 2.0 / 4e15 * 100
+        cfg=cfg, model=model, window={"cell_rounds": 100},
+        trace={"window_s": 2.0}, chips=4, peaks={"bf16_flops_per_s": 1e15})
+    want = 100 * flops.cell_round_flops(cfg, model) / 2.0 / 4e15 * 100
     assert reg.reader("mfu").read(run) == pytest.approx(want)
 
 
@@ -239,17 +242,22 @@ def test_shares_and_zipf():
 # ------------------------------------------------------------- registry
 
 def test_registry_finds_pieces_from_files_alone(tmp_path):
-    """A new configuration, traffic mix, cell and metric are files and
-    entries: nothing that is there is edited."""
+    """A new configuration, model, traffic mix, cell and metric are files
+    and entries: nothing that is there is edited."""
     bench = tmp_path / "b"
-    for sub in ("configs", "traffic", "limits", "drivers", "metrics"):
+    for sub in ("configs", "models", "traffic", "limits", "drivers",
+                "metrics"):
         (bench / sub).mkdir(parents=True)
-    (bench / "configs" / "m.json").write_text('{"n_sov": 3}')
+    (bench / "configs" / "m.json").write_text(
+        '{"n_sov": 3, "model": {"name": "toy", "width": 4}}')
+    (bench / "models" / "toy.py").write_text(
+        "def weights(key, m):\n    return [key] * m['width']\n")
     (bench / "traffic" / "t.json").write_text('{"driver": "d", "x": 1}')
     (bench / "limits" / "m.t.json").write_text('{"limits": {"a": 0}}')
     (bench / "drivers" / "d.py").write_text(
-        "def build(cfg, traffic, seed, fault=''):\n"
-        "    return (cfg['n_sov'], traffic['x'], seed)\n")
+        "def build(cfg, model, traffic, seed, fault=''):\n"
+        "    return (cfg['n_sov'], model.weights(0, cfg['model']),\n"
+        "            traffic['x'], seed)\n")
     (bench / "metrics" / "q.share.py").write_text(
         "def read(run):\n    return run\n")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps({
@@ -263,7 +271,9 @@ def test_registry_finds_pieces_from_files_alone(tmp_path):
     reg = Registry(tmp_path, bench)
     w = reg.workload("m.t")
     cfg, tr = reg.config(w["config"]), reg.traffic(w["traffic"])
-    assert reg.driver(tr["driver"]).build(cfg, tr, 7) == (3, 1, 7)
+    model = reg.model(cfg["model"]["name"])
+    assert reg.driver(tr["driver"]).build(cfg, model, tr, 7) == \
+        (3, [0, 0, 0, 0], 1, 7)
     assert [m["name"] for m in reg.metrics_of("m.t", "per_layer")] == \
         ["q.share"]
     assert [m["name"] for m in reg.metrics_of("m.t", "end_to_end")] == ["e"]
@@ -273,6 +283,8 @@ def test_registry_finds_pieces_from_files_alone(tmp_path):
         reg.workload("nope")
     with pytest.raises(RegistryError):
         reg.reader("nope")
+    with pytest.raises(RegistryError):
+        reg.model("nope")
 
 
 def test_benchmark_json_keeps_to_its_contract():
